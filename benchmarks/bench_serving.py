@@ -32,11 +32,12 @@ dispatch) and emits ``BENCH_serving.json``:
   Cells report ``capacity_tokens`` / ``max_concurrent_seqs`` / swap
   counts plus ``greedy_agreement`` — the int8 cell's token-level match
   against the fp cell's greedy outputs — both gated by ``compare.py``.
-* **mesh** cells — tensor-parallel paged serving over a forced-host
-  2x2 device mesh (4 CPU devices, KV-head axis sharded over the model
-  axis).  Each cell runs in a subprocess (``XLA_FLAGS`` must force the
-  device count before jax initializes) that serves the same greedy
-  workload unsharded and on the mesh; the cell reports the mesh run's
+* **mesh** cells — tensor-parallel paged serving over a 2x2 device
+  mesh (KV-head axis sharded over the model axis).  On the CPU each cell
+  runs in a subprocess (``XLA_FLAGS`` must force 4 host devices before
+  jax initializes); on an accelerator it runs in this process over its
+  devices.  The cell serves the same greedy workload unsharded and on
+  the mesh, and reports the mesh run's
   throughput plus ``greedy_agreement`` — its token-level match against
   the unsharded outputs, which the sharded dispatch keeps bit-identical
   (gated by ``compare.py``).
@@ -202,101 +203,115 @@ def bench_kv_dtype(arch: str, kv_dtype: str, n_requests: int, n_lanes: int,
     return row, outputs
 
 
-# runs in a child interpreter: XLA_FLAGS (forced host device count) only
-# takes effect before jax initializes, and the parent has already imported
-# jax by the time the mesh cells run
-_MESH_CHILD = r"""
-import json, sys, time
-import numpy as np
+def _mesh_cell(cell: dict) -> dict:
+    """Serve one greedy workload unsharded and on ``cell["mesh"]`` in this
+    process, over whatever devices ``jax.devices()`` reports; returns the
+    mesh run's row with its ``greedy_agreement`` against the unsharded
+    outputs."""
+    import jax
+    import numpy as np
 
-cfg_in = json.loads(sys.argv[1])
-sys.path.insert(0, cfg_in["src"])
-import jax
-from repro.configs import get_arch
-from repro.distributed.sharding import make_serving_mesh
-from repro.models import build_model
-from repro.serving import Request, ServingEngine
+    from repro.configs import get_arch
+    from repro.distributed.sharding import make_serving_mesh
+    from repro.models import build_model
+    from repro.serving import Request, ServingEngine
 
-arch = cfg_in["arch"]
-acfg = get_arch(arch).reduced()
-model = build_model(acfg)
-params = model.init(jax.random.PRNGKey(cfg_in["seed"]))
+    acfg = get_arch(cell["arch"]).reduced()
+    model = build_model(acfg)
+    params = model.init(jax.random.PRNGKey(cell["seed"]))
 
-def run(mesh_spec):
-    engine = ServingEngine(model, params, n_lanes=cfg_in["lanes"],
-                           max_len=cfg_in["max_len"], cache="paged",
-                           page_size=cfg_in["page_size"],
-                           prefill_chunk=cfg_in["prefill_chunk"],
-                           mesh=make_serving_mesh(mesh_spec))
-    rng = np.random.default_rng(cfg_in["seed"])
-    t0 = time.time()
-    for rid in range(cfg_in["requests"]):
-        prompt = rng.integers(0, acfg.vocab_size,
-                              size=int(rng.integers(4, 12))).tolist()
-        engine.submit(Request(rid=rid, prompt=prompt,
-                              max_new_tokens=cfg_in["max_new"]))
-    finished = engine.run(
-        max_steps=cfg_in["requests"] * (cfg_in["max_new"] + 6))
-    wall = time.time() - t0
+    def run(mesh_spec):
+        engine = ServingEngine(model, params, n_lanes=cell["lanes"],
+                               max_len=cell["max_len"], cache="paged",
+                               page_size=cell["page_size"],
+                               prefill_chunk=cell["prefill_chunk"],
+                               mesh=make_serving_mesh(mesh_spec))
+        rng = np.random.default_rng(cell["seed"])
+        t0 = time.time()
+        for rid in range(cell["requests"]):
+            prompt = rng.integers(0, acfg.vocab_size,
+                                  size=int(rng.integers(4, 12))).tolist()
+            engine.submit(Request(rid=rid, prompt=prompt,
+                                  max_new_tokens=cell["max_new"]))
+        finished = engine.run(
+            max_steps=cell["requests"] * (cell["max_new"] + 6))
+        wall = time.time() - t0
+        outs = {int(r.rid): [int(t) for t in r.out_tokens]
+                for r in finished}
+        return engine, outs, wall, len(finished)
+
+    _, ref_outs, _, _ = run(None)
+    engine, outs, wall, n_fin = run(cell["mesh"])
     s = engine.metrics.summary()
-    outs = {int(r.rid): [int(t) for t in r.out_tokens] for r in finished}
-    return engine, s, outs, wall, len(finished)
+    match = total = 0
+    for rid, ref in ref_outs.items():
+        got = outs.get(rid, [])
+        total += max(len(ref), len(got))
+        match += sum(a == b for a, b in zip(ref, got))
+    return {
+        "n_devices": len(jax.devices()),
+        "finished": n_fin,
+        "decode_steps": engine.steps,
+        "prefill_chunks": engine.prefill_chunks,
+        "generated_tokens": s["generated_tokens"],
+        "tokens_per_s": s["generated_tokens"] / wall if wall else 0.0,
+        "ttft_p50_s": s["ttft_s"]["p50"], "ttft_p99_s": s["ttft_s"]["p99"],
+        "itl_p50_s": s["itl_s"]["p50"], "itl_p99_s": s["itl_s"]["p99"],
+        "preemptions": s["preemptions"],
+        "greedy_agreement": match / total if total else 1.0,
+        "wall_s": wall,
+    }
 
-_, _, ref_outs, _, _ = run(None)
-engine, s, outs, wall, n_fin = run(cfg_in["mesh"])
-match = total = 0
-for rid, ref in ref_outs.items():
-    got = outs.get(rid, [])
-    total += max(len(ref), len(got))
-    match += sum(a == b for a, b in zip(ref, got))
-print("MESH_ROW " + json.dumps({
-    "n_devices": len(jax.devices()),
-    "finished": n_fin,
-    "decode_steps": engine.steps,
-    "prefill_chunks": engine.prefill_chunks,
-    "generated_tokens": s["generated_tokens"],
-    "tokens_per_s": s["generated_tokens"] / wall if wall else 0.0,
-    "ttft_p50_s": s["ttft_s"]["p50"], "ttft_p99_s": s["ttft_s"]["p99"],
-    "itl_p50_s": s["itl_s"]["p50"], "itl_p99_s": s["itl_s"]["p99"],
-    "preemptions": s["preemptions"],
-    "greedy_agreement": match / total if total else 1.0,
-    "wall_s": wall,
-}))
+
+# CPU only: XLA_FLAGS (forced host device count) takes effect only before
+# jax initializes, and this process has already run jax cells
+_MESH_CHILD = r"""
+import json, sys
+sys.path[:0] = sys.argv[2:4]
+from bench_serving import _mesh_cell
+print("MESH_ROW " + json.dumps(_mesh_cell(json.loads(sys.argv[1]))))
 """
 
 
 def bench_mesh(arch: str, mesh: str, n_requests: int, n_lanes: int,
                max_len: int, max_new: int, page_size: int,
                prefill_chunk: int, seed: int = 0) -> dict:
-    """Tensor-parallel paged serving on a forced-host device mesh.
+    """Tensor-parallel paged serving on a device mesh.
 
-    The subprocess serves the identical greedy workload unsharded and on
-    the mesh, so ``greedy_agreement`` scores the sharded dispatch
-    against single-device truth (1.0 = bit-identical, the design
-    invariant the kernels' head-sharded shard_map + exact all-gather
-    guarantees)."""
+    The cell serves the identical greedy workload unsharded and on the
+    mesh, so ``greedy_agreement`` scores the sharded dispatch against
+    single-device truth (1.0 = bit-identical, the design invariant the
+    kernels' head-sharded shard_map + exact all-gather guarantees).  On
+    an accelerator the cell runs in this process over its devices (a
+    chip belongs to the one process that opened it); on the CPU a child
+    interpreter forces the host device count."""
     import subprocess
 
-    devices = 1
-    for d in mesh.split("x"):
-        devices *= int(d)
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    child_cfg = {"src": env["PYTHONPATH"], "arch": arch, "mesh": mesh,
-                 "requests": n_requests, "lanes": n_lanes,
-                 "max_len": max_len, "max_new": max_new,
-                 "page_size": page_size, "prefill_chunk": prefill_chunk,
-                 "seed": seed}
-    proc = subprocess.run(
-        [sys.executable, "-c", _MESH_CHILD, json.dumps(child_cfg)],
-        capture_output=True, text=True, timeout=900, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh bench child failed:\n{proc.stderr}")
-    line = next(ln for ln in proc.stdout.splitlines()
-                if ln.startswith("MESH_ROW "))
-    row = json.loads(line[len("MESH_ROW "):])
+    import jax
+
+    cell = {"arch": arch, "mesh": mesh, "requests": n_requests,
+            "lanes": n_lanes, "max_len": max_len, "max_new": max_new,
+            "page_size": page_size, "prefill_chunk": prefill_chunk,
+            "seed": seed}
+    if jax.default_backend() != "cpu":
+        row = _mesh_cell(cell)
+    else:
+        devices = 1
+        for d in mesh.split("x"):
+            devices *= int(d)
+        here = os.path.dirname(os.path.abspath(__file__))
+        src = os.path.join(here, "..", "src")
+        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_"
+                   f"device_count={devices}")
+        proc = subprocess.run(
+            [sys.executable, "-c", _MESH_CHILD, json.dumps(cell), src,
+             here],
+            capture_output=True, text=True, timeout=900, env=env)
+        if proc.returncode != 0:
+            raise RuntimeError(f"mesh bench child failed:\n{proc.stderr}")
+        line = next(ln for ln in proc.stdout.splitlines()
+                    if ln.startswith("MESH_ROW "))
+        row = json.loads(line[len("MESH_ROW "):])
     return {"arch": arch, "cache": "paged", "workload": "mesh",
             "mesh": mesh, "prefill_chunk": prefill_chunk,
             "n_lanes": n_lanes, "requests": n_requests, **row}

@@ -22,16 +22,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
+
 from .cost import eval_expr
 from .errors import OATSpecError
-
-
-def _block(x: Any) -> None:
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
 
 
 @dataclass
@@ -39,7 +33,9 @@ class WallClockExecutor:
     """cost = min wall-clock seconds over ``repeats`` runs of the variant.
 
     ``make_variant(assignment)`` returns a zero-arg callable; its result is
-    blocked on (JAX async dispatch) before the clock stops.
+    blocked on (JAX async dispatch) before the clock stops.  A device
+    error raised while blocking propagates: a variant that failed must
+    not be timed as a fast one.
     """
 
     make_variant: Callable[[dict], Callable[[], Any]]
@@ -49,11 +45,11 @@ class WallClockExecutor:
     def __call__(self, assignment: dict[str, Any]) -> float:
         fn = self.make_variant(assignment)
         for _ in range(self.warmup):
-            _block(fn())
+            jax.block_until_ready(fn())
         best = float("inf")
         for _ in range(self.repeats):
             t0 = time.perf_counter()
-            _block(fn())
+            jax.block_until_ready(fn())
             best = min(best, time.perf_counter() - t0)
         return best
 

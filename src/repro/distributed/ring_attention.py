@@ -25,11 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                       # jax < 0.6 export location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def make_ring_attention(mesh: Mesh, axis_name: str = "model",
                         causal: bool = True, scale: float | None = None,
@@ -53,10 +48,7 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "model",
         # values; jax's vma type system requires matching carry types)
         axes = (axis_name,) if batch_axis is None \
             else (axis_name, batch_axis)
-        if hasattr(jax.lax, "pcast"):
-            mk = lambda x: jax.lax.pcast(x, axes, to="varying")
-        else:       # jax < 0.6: no varying-manual-axes type system
-            mk = lambda x: x
+        mk = lambda x: jax.lax.pcast(x, axes, to="varying")
         m0 = mk(jnp.full((b, h, s_loc), -1e30, jnp.float32))
         l0 = mk(jnp.zeros((b, h, s_loc), jnp.float32))
         a0 = mk(jnp.zeros((b, h, s_loc, d), jnp.float32))
@@ -86,7 +78,7 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "model",
         return (acc / l[..., None]).astype(q.dtype)
 
     spec = P(batch_axis, None, axis_name, None)
-    return _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec)
 
 

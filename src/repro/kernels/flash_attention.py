@@ -32,10 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -148,7 +144,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -244,7 +240,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, k, v, kv_len)
@@ -284,46 +280,49 @@ def _split_combine_kernel(m_ref, l_ref, acc_ref, o_ref):
     """Phase 2 of split-KV attention: merge per-split partial softmax
     states with the standard max-shift rescale, then normalize.
 
-    Blocks: m, l (1, 1, ns, rows); acc (1, 1, ns, rows, d); out
-    (1, 1, rows, d).  An empty split carries (m=NEG_INF, l=0, acc=0):
-    its rescale weight exp(NEG_INF - m*) underflows to exactly 0.0, so
-    it contributes nothing; if *every* split is empty the l* == 0 guard
-    reproduces the sequential kernel's zero output.
+    Blocks: m, l (1, 1, ns, rows, 1); acc (1, 1, ns, rows, d); out
+    (1, 1, rows, d).  The trailing unit axis keeps m/l in the (rows, 1)
+    column layout of the phase-1 scratch, which the TPU block tiling
+    accepts and which broadcasts against acc with no relayout.  An empty
+    split carries (m=NEG_INF, l=0, acc=0): its rescale weight
+    exp(NEG_INF - m*) underflows to exactly 0.0, so it contributes
+    nothing; if *every* split is empty the l* == 0 guard reproduces the
+    sequential kernel's zero output.
     """
-    m = m_ref[0, 0]                                  # (ns, rows)
-    l = l_ref[0, 0]                                  # (ns, rows)
+    m = m_ref[0, 0]                                  # (ns, rows, 1)
+    l = l_ref[0, 0]                                  # (ns, rows, 1)
     acc = acc_ref[0, 0]                              # (ns, rows, d)
-    m_star = m.max(axis=0, keepdims=True)            # (1, rows)
-    alpha = jnp.exp(m - m_star)                      # (ns, rows)
-    l_star = (l * alpha).sum(axis=0, keepdims=True)  # (1, rows)
-    acc_star = (acc * alpha[..., None]).sum(axis=0)  # (rows, d)
+    m_star = m.max(axis=0, keepdims=True)            # (1, rows, 1)
+    alpha = jnp.exp(m - m_star)                      # (ns, rows, 1)
+    l_star = (l * alpha).sum(axis=0)                 # (rows, 1)
+    acc_star = (acc * alpha).sum(axis=0)             # (rows, d)
     l_star = jnp.where(l_star == 0.0, 1.0, l_star)
-    o_ref[0, 0] = (acc_star / l_star[0][:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc_star / l_star).astype(o_ref.dtype)
 
 
 def _combine_splits(m: jax.Array, l: jax.Array, acc: jax.Array,
                     out_dtype, interpret: bool) -> jax.Array:
     """Run the combine kernel over canonical partial-state arrays.
 
-    m, l: (B, R, ns, rows) fp32; acc: (B, R, ns, rows, d) fp32, where R
-    is whatever the phase-1 grid parallelised over besides batch (kv
+    m, l: (B, R, ns, rows, 1) fp32; acc: (B, R, ns, rows, d) fp32, where
+    R is whatever the phase-1 grid parallelised over besides batch (kv
     heads for decode, head x q-tile for prefill).  Returns
     (B, R, rows, d) in ``out_dtype``.
     """
-    bb, rr, ns, rows = m.shape
+    bb, rr, ns, rows, _ = m.shape
     d = acc.shape[-1]
     return pl.pallas_call(
         _split_combine_kernel,
         grid=(bb, rr),
         in_specs=[
-            pl.BlockSpec((1, 1, ns, rows), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, ns, rows), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, ns, rows, 1), lambda i, j: (i, j, 0, 0, 0)),
+            pl.BlockSpec((1, 1, ns, rows, 1), lambda i, j: (i, j, 0, 0, 0)),
             pl.BlockSpec((1, 1, ns, rows, d),
                          lambda i, j: (i, j, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bb, rr, rows, d), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(m, l, acc)
@@ -424,8 +423,8 @@ def _paged_decode_split_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == steps_per_split - 1)
     def _done():
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0] = acc_ref[...]
 
 
@@ -492,7 +491,7 @@ def flash_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
@@ -518,12 +517,12 @@ def flash_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             pl.BlockSpec((1, 1, bk, d), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, g),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda bb, hh, isp, j, tbl, ln:
-                         (bb, hh, isp, 0)),
-            pl.BlockSpec((1, 1, 1, g),
+                         (bb, hh, isp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda bb, hh, isp, j, tbl, ln:
-                         (bb, hh, isp, 0)),
+                         (bb, hh, isp, 0, 0)),
             pl.BlockSpec((1, 1, 1, g, d),
                          lambda bb, hh, isp, j, tbl, ln:
                          (bb, hh, isp, 0, 0)),
@@ -536,11 +535,11 @@ def flash_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, ns, g, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -657,8 +656,8 @@ def _paged_prefill_split_kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref,
 
     @pl.when(j == steps_per_split - 1)
     def _done():
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0] = acc_ref[...]
 
 
@@ -734,7 +733,7 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, cp, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
@@ -753,7 +752,7 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         return (tbl[bb, ik // s], hh // g, ik % s, 0)
 
     def row_idx(bb, hh, iq, isp, j, tbl, st, ln, ns=ns):
-        return (bb, hh, iq * ns + isp, 0)
+        return (bb, hh, iq * ns + isp, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -766,11 +765,9 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             pl.BlockSpec((1, 1, bk, d), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, bq), row_idx),
-            pl.BlockSpec((1, 1, 1, bq), row_idx),
-            pl.BlockSpec((1, 1, 1, bq, d),
-                         lambda bb, hh, iq, isp, j, tbl, st, ln, ns=ns:
-                         (bb, hh, iq * ns + isp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bq, 1), row_idx),
+            pl.BlockSpec((1, 1, 1, bq, 1), row_idx),
+            pl.BlockSpec((1, 1, 1, bq, d), row_idx),
         ],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
@@ -780,11 +777,11 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, nq * ns, bq), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, nq * ns, bq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nq * ns, bq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nq * ns, bq, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, h, nq * ns, bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
@@ -792,8 +789,8 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       kv_len.astype(jnp.int32), q, k_pool, v_pool)
     # fold (h, nq) into the combine's R axis; the nq*ns rows are laid out
     # q-tile-major so a plain reshape separates them
-    out = _combine_splits(pm.reshape(b, h * nq, ns, bq),
-                          pll.reshape(b, h * nq, ns, bq),
+    out = _combine_splits(pm.reshape(b, h * nq, ns, bq, 1),
+                          pll.reshape(b, h * nq, ns, bq, 1),
                           pacc.reshape(b, h * nq, ns, bq, d),
                           q.dtype, interpret)
     return out.reshape(b, h, cp, d)[:, :, :c, :]
@@ -803,6 +800,17 @@ def flash_paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 # quantized paged kernels: int8 pages + per-row fp32 scales, dequantized
 # in-kernel into the same fp32 online-softmax accumulator path
 # --------------------------------------------------------------------------
+
+
+def _scale_columns(k_scale: jax.Array, v_scale: jax.Array):
+    """(P, Hkv, psz) per-row scales -> (P, Hkv, psz, 1) column view.
+
+    A (1, 1, bk) block of the 3-D scales is refused by the TPU block
+    tiling (its second-minor dim 1 neither spans Hkv nor is a multiple
+    of 8); the (1, 1, bk, 1) column block is accepted and lands in VMEM
+    already shaped to broadcast over the (bk, d) key/value tile rows.
+    """
+    return k_scale[..., None], v_scale[..., None]
 
 
 def _paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
@@ -825,7 +833,7 @@ def _paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
         q = q_ref[0, 0].astype(jnp.float32)        # (G, d)
         # int8 tile * per-row scale -> fp32 keys; rest identical to the
         # fp kernel (the accumulator path never sees int8)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -836,7 +844,7 @@ def _paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
         alpha = jnp.exp(m_prev - m_cur)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_cur
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
 
@@ -870,7 +878,7 @@ def _paged_decode_split_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
     @pl.when(jnp.logical_and(ik < n_steps, k_start < kv_len))
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)        # (G, d)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -881,14 +889,14 @@ def _paged_decode_split_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
         alpha = jnp.exp(m_prev - m_cur)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_cur
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == steps_per_split - 1)
     def _done():
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0] = acc_ref[...]
 
 
@@ -918,6 +926,7 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
     scale = float(scale if scale is not None else d ** -0.5)
     bk, sub = _resolve_block_k(block_k, psz, "flash_paged_decode_quant")
     qg = q.reshape(b, hkv, g, d)
+    k_scale, v_scale = _scale_columns(k_scale, v_scale)
     n_steps = nblk * sub
     ns, sps = _num_splits(num_splits, n_steps)
     if ns == 1:
@@ -927,9 +936,9 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
         pool_spec = pl.BlockSpec((1, 1, bk, d),
                                  lambda bb, hh, ik, tbl, ln, s=sub:
                                  (tbl[bb, ik // s], hh, ik % s, 0))
-        scale_spec = pl.BlockSpec((1, 1, bk),
+        scale_spec = pl.BlockSpec((1, 1, bk, 1),
                                   lambda bb, hh, ik, tbl, ln, s=sub:
-                                  (tbl[bb, ik // s], hh, ik % s))
+                                  (tbl[bb, ik // s], hh, ik % s, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -952,7 +961,7 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
@@ -970,7 +979,7 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
 
     def sc_idx(bb, hh, isp, j, tbl, ln, s=sub, sp=sps, n=n_steps):
         ik = jnp.minimum(isp * sp + j, n - 1)
-        return (tbl[bb, ik // s], hh, ik % s)
+        return (tbl[bb, ik // s], hh, ik % s, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -980,16 +989,16 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
                          lambda bb, hh, isp, j, tbl, ln: (bb, hh, 0, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_idx),
             pl.BlockSpec((1, 1, bk, d), kv_idx),
-            pl.BlockSpec((1, 1, bk), sc_idx),
-            pl.BlockSpec((1, 1, bk), sc_idx),
+            pl.BlockSpec((1, 1, bk, 1), sc_idx),
+            pl.BlockSpec((1, 1, bk, 1), sc_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, g),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda bb, hh, isp, j, tbl, ln:
-                         (bb, hh, isp, 0)),
-            pl.BlockSpec((1, 1, 1, g),
+                         (bb, hh, isp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g, 1),
                          lambda bb, hh, isp, j, tbl, ln:
-                         (bb, hh, isp, 0)),
+                         (bb, hh, isp, 0, 0)),
             pl.BlockSpec((1, 1, 1, g, d),
                          lambda bb, hh, isp, j, tbl, ln:
                          (bb, hh, isp, 0, 0)),
@@ -1002,11 +1011,11 @@ def flash_paged_decode_quant(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, ns, g, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -1038,7 +1047,7 @@ def _paged_prefill_quant_kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref,
     @pl.when(live)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)       # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qi = q_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -1053,7 +1062,7 @@ def _paged_prefill_quant_kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref,
         alpha = jnp.exp(m_prev - m_cur)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_cur
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
 
@@ -1094,7 +1103,7 @@ def _paged_prefill_split_quant_kernel(tbl_ref, start_ref, len_ref, q_ref,
     @pl.when(live)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)       # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qi = q_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -1109,14 +1118,14 @@ def _paged_prefill_split_quant_kernel(tbl_ref, start_ref, len_ref, q_ref,
         alpha = jnp.exp(m_prev - m_cur)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_cur
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == steps_per_split - 1)
     def _done():
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0] = acc_ref[...]
 
 
@@ -1149,6 +1158,7 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0)))   # rows discard
     cp = q.shape[2]
     bk, sub = _resolve_block_k(block_k, psz, "flash_paged_prefill_quant")
+    k_scale, v_scale = _scale_columns(k_scale, v_scale)
     n_steps = nblk * sub
     nq = cp // bq
     ns, sps = _num_splits(num_splits, n_steps)
@@ -1161,9 +1171,9 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
             lambda bb, hh, iq, ik, tbl, st, ln, g=g, s=sub:
             (tbl[bb, ik // s], hh // g, ik % s, 0))
         scale_spec = pl.BlockSpec(
-            (1, 1, bk),
+            (1, 1, bk, 1),
             lambda bb, hh, iq, ik, tbl, st, ln, g=g, s=sub:
-            (tbl[bb, ik // s], hh // g, ik % s))
+            (tbl[bb, ik // s], hh // g, ik % s, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -1187,7 +1197,7 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, cp, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
@@ -1208,10 +1218,10 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
     def sc_idx(bb, hh, iq, isp, j, tbl, st, ln, g=g, s=sub, sp=sps,
                n=n_steps):
         ik = jnp.minimum(isp * sp + j, n - 1)
-        return (tbl[bb, ik // s], hh // g, ik % s)
+        return (tbl[bb, ik // s], hh // g, ik % s, 0)
 
     def row_idx(bb, hh, iq, isp, j, tbl, st, ln, ns=ns):
-        return (bb, hh, iq * ns + isp, 0)
+        return (bb, hh, iq * ns + isp, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1222,15 +1232,13 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
                          (bb, hh, iq, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_idx),
             pl.BlockSpec((1, 1, bk, d), kv_idx),
-            pl.BlockSpec((1, 1, bk), sc_idx),
-            pl.BlockSpec((1, 1, bk), sc_idx),
+            pl.BlockSpec((1, 1, bk, 1), sc_idx),
+            pl.BlockSpec((1, 1, bk, 1), sc_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, bq), row_idx),
-            pl.BlockSpec((1, 1, 1, bq), row_idx),
-            pl.BlockSpec((1, 1, 1, bq, d),
-                         lambda bb, hh, iq, isp, j, tbl, st, ln, ns=ns:
-                         (bb, hh, iq * ns + isp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bq, 1), row_idx),
+            pl.BlockSpec((1, 1, 1, bq, 1), row_idx),
+            pl.BlockSpec((1, 1, 1, bq, d), row_idx),
         ],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
@@ -1240,18 +1248,18 @@ def flash_paged_prefill_quant(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, nq * ns, bq), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, nq * ns, bq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nq * ns, bq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nq * ns, bq, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, h, nq * ns, bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
       kv_len.astype(jnp.int32), q, k_pool, v_pool, k_scale, v_scale)
-    out = _combine_splits(pm.reshape(b, h * nq, ns, bq),
-                          pll.reshape(b, h * nq, ns, bq),
+    out = _combine_splits(pm.reshape(b, h * nq, ns, bq, 1),
+                          pll.reshape(b, h * nq, ns, bq, 1),
                           pacc.reshape(b, h * nq, ns, bq, d),
                           q.dtype, interpret)
     return out.reshape(b, h, cp, d)[:, :, :c, :]

@@ -40,11 +40,6 @@ from .flash_attention import (flash_attention, flash_decode,
 from .matmul import matmul
 from .ssm_scan import selective_scan
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                       # jax < 0.6 export location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def set_tuned(name: str, **pps) -> None:
     """Deprecated: use ``repro.at.publish`` (kept as a thin shim)."""
@@ -208,10 +203,10 @@ def _head_sharded(mesh, fn, q, pools: PagedPools, *rest):
     computes bitwise identically to the unsharded engine.
     """
     qspec = P(None, "model", None, None)
-    shardfn = _shard_map(fn, mesh=mesh,
-                         in_specs=(qspec, pools.head_specs(),
-                                   *(P() for _ in rest)),
-                         out_specs=qspec, check_rep=False)
+    shardfn = jax.shard_map(fn, mesh=mesh,
+                            in_specs=(qspec, pools.head_specs(),
+                                      *(P() for _ in rest)),
+                            out_specs=qspec, check_vma=False)
     out = shardfn(q, pools, *rest)
     return jax.lax.with_sharding_constraint(
         out, NamedSharding(mesh, P(None, None, None, None)))

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_ref, *,
                  chunk: int):
@@ -85,7 +81,7 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((1, ch, di), lambda bb, ic: (bb, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, lp, di), x.dtype),
         scratch_shapes=[pltpu.VMEM((di, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, b, c, d)

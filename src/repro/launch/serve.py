@@ -1,7 +1,9 @@
 """Serving driver: scheduler-driven engine + run-time auto-tuning.
 
-CPU-scale (reduced configs): submits a stream of synthetic requests,
-reports throughput/latency percentiles from the serving metrics layer,
+Submits a stream of synthetic requests to the reduced (CPU smoke-test)
+config of ``--arch``, or with ``--full`` to its published widths (bf16
+weights, ``--layers N`` to cut the depth), reports throughput/latency
+percentiles from the serving metrics layer,
 and demonstrates the run-time AT path (decode bucket variants tuned on
 the first calls through a ``repro.at`` session, then committed; committed
 winners persist in the session's record store, so a restarted server
@@ -54,12 +56,14 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import at
 from ..configs import get_arch
 from ..distributed.sharding import make_serving_mesh
 from ..models import build_model
 from ..serving import REDUCED_BUCKETS, Request, SamplingParams, ServingEngine
+from . import enable_compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,9 +76,14 @@ class ServeConfig:
     bench payload config, so ``compare.py`` cell keys and the report both
     derive from the same record.  ``mesh`` is the tensor-parallel device
     mesh spec (``"RxC"``, data x model — e.g. ``"1x4"``; None = unsharded).
+    ``reduced`` serves the CPU smoke-test widths of ``arch`` (the default);
+    False serves its published widths.  ``layers`` cuts the depth in whole
+    layers (None keeps the config's own depth).
     """
 
     arch: str = "yi-6b"
+    reduced: bool = True
+    layers: int | None = None
     n_requests: int = 8
     n_lanes: int = 4
     max_len: int = 96
@@ -126,6 +135,35 @@ class ServeConfig:
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+
+def serving_arch_config(scfg: ServeConfig):
+    """The architecture a serve run builds: the reduced or published
+    config of ``scfg.arch``, depth-cut to ``scfg.layers``.  Published
+    configs serve with weights held in the compute dtype: fp32 master
+    weights are a training concern and would double the device bytes
+    (yi-6b: 24.2 GB in fp32 against 16 GB of HBM on one v5e chip)."""
+    cfg = get_arch(scfg.arch)
+    if scfg.reduced:
+        cfg = cfg.reduced()
+    else:
+        cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+    if scfg.layers is not None:
+        if not 1 <= scfg.layers <= cfg.n_layers:
+            raise ValueError(f"layers={scfg.layers} outside 1..{cfg.n_layers}"
+                             f" for {cfg.name!r}")
+        cfg = dataclasses.replace(cfg, n_layers=scfg.layers)
+    return cfg
+
+
+def init_serving_params(model, seed: int, mesh=None):
+    """Random weights made on the device under ``jit``: the RNG
+    temporaries stay inside one program instead of landing on the device
+    as eager full-size arrays next to the weights.  With a mesh the
+    weights come out replicated on every device of it (only the KV pools
+    shard), so no step has to copy them there."""
+    out = None if mesh is None else NamedSharding(mesh, PartitionSpec())
+    return jax.jit(model.init, out_shardings=out)(jax.random.PRNGKey(seed))
 
 
 def _make_kv_precision_bench(model, page_size: int, lanes: int = 2,
@@ -470,27 +508,23 @@ def serve(**kwargs) -> dict:
     return serve_config(ServeConfig(**kwargs))
 
 
-def serve_config(scfg: ServeConfig) -> dict:
-    arch, n_requests, n_lanes = scfg.arch, scfg.n_requests, scfg.n_lanes
-    max_len, prompt_len, max_new = scfg.max_len, scfg.prompt_len, scfg.max_new
+def build_serving(scfg: ServeConfig):
+    """Model, weights, tuner and engine of a serve run, plus its synthetic
+    prompts: everything :func:`serve_config` drives, for callers that
+    drive the engine tick by tick.  Returns ``(engine, tuner, prompts)``."""
+    n_lanes, max_len, prompt_len = scfg.n_lanes, scfg.max_len, scfg.prompt_len
     seed, autotune, workdir = scfg.seed, scfg.autotune, scfg.workdir
     cache, n_pages, page_size = scfg.cache, scfg.n_pages, scfg.page_size
-    timeslice, prefill_chunk = scfg.timeslice, scfg.prefill_chunk
-    draft, spec_k = scfg.draft, scfg.spec_k
-    temperature, top_k, top_p = scfg.temperature, scfg.top_k, scfg.top_p
-    prefix_cache, shared_prefix = scfg.prefix_cache, scfg.shared_prefix
-    gateway, port, queue_limit = scfg.gateway, scfg.port, scfg.queue_limit
-    policy_window = scfg.policy_window
-    slo_ttft_s, slo_itl_s = scfg.slo_ttft_s, scfg.slo_itl_s
+    prefill_chunk, draft, spec_k = scfg.prefill_chunk, scfg.draft, scfg.spec_k
     kv_dtype = scfg.kv_dtype
     if kv_dtype not in ("fp", "int8", "auto"):
         raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
     if kv_dtype == "auto" and not (cache == "paged" and autotune):
         raise ValueError("--kv-dtype auto needs --cache paged --autotune")
     mesh = make_serving_mesh(scfg.mesh)
-    cfg = get_arch(arch).reduced()
+    cfg = serving_arch_config(scfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
+    params = init_serving_params(model, seed, mesh)
     if scfg.num_splits is not None and cache == "paged":
         # forced split-KV degree: published before any engine or variant
         # jit trace so the tuned and untuned paths both read it
@@ -505,10 +539,10 @@ def serve_config(scfg: ServeConfig) -> dict:
         draft_model = model.draft_model()
         draft_params = model.slice_draft_params(params, draft_model)
     tuner = _make_autotuner(model, workdir, cache, page_size,
-                            gateway=gateway,
+                            gateway=scfg.gateway,
                             prefill_chunk=prefill_chunk,
                             spec_k=spec_k if draft else None,
-                            prefix_cache=prefix_cache,
+                            prefix_cache=scfg.prefix_cache,
                             kv_precision=kv_dtype == "auto",
                             num_splits=scfg.num_splits,
                             mesh=mesh, mesh_shape=scfg.mesh,
@@ -528,28 +562,37 @@ def serve_config(scfg: ServeConfig) -> dict:
         resolved_kv = tuner.resolve_kv_dtype()
     engine = ServingEngine(model, params, n_lanes=n_lanes, max_len=max_len,
                            autotuner=tuner, cache=cache, n_pages=n_pages,
-                           page_size=page_size, timeslice=timeslice,
+                           page_size=page_size, timeslice=scfg.timeslice,
                            prefill_chunk=prefill_chunk,
                            draft_model=draft_model,
                            draft_params=draft_params,
                            spec_k=spec_k if draft else None,
-                           prefix_cache=prefix_cache,
+                           prefix_cache=scfg.prefix_cache,
                            kv_dtype=resolved_kv, mesh=mesh)
     rng = np.random.default_rng(seed)
     # shared_prefix > 0 prepends one common system prompt to every
     # request — the workload that makes the prefix cache earn its keep
     prefix = rng.integers(0, cfg.vocab_size,
-                          size=shared_prefix).tolist() if shared_prefix \
-        else []
+                          size=scfg.shared_prefix).tolist() \
+        if scfg.shared_prefix else []
     prompts = [prefix + rng.integers(
         0, cfg.vocab_size, size=rng.integers(4, prompt_len)).tolist()
-        for _ in range(n_requests)]
+        for _ in range(scfg.n_requests)]
+    return engine, tuner, prompts
+
+
+def serve_config(scfg: ServeConfig) -> dict:
+    n_requests, max_new, seed = scfg.n_requests, scfg.max_new, scfg.seed
+    draft, shared_prefix, gateway = scfg.draft, scfg.shared_prefix, \
+        scfg.gateway
+    temperature, top_k, top_p = scfg.temperature, scfg.top_k, scfg.top_p
+    engine, tuner, prompts = build_serving(scfg)
     gateway_report = None
     if gateway:
         finished, gateway_report = _serve_gateway(
-            engine, tuner, prompts, max_new=max_new, port=port,
-            queue_limit=queue_limit, policy_window=policy_window,
-            slo_ttft_s=slo_ttft_s, slo_itl_s=slo_itl_s,
+            engine, tuner, prompts, max_new=max_new, port=scfg.port,
+            queue_limit=scfg.queue_limit, policy_window=scfg.policy_window,
+            slo_ttft_s=scfg.slo_ttft_s, slo_itl_s=scfg.slo_itl_s,
             temperature=temperature, top_k=top_k, top_p=top_p, seed=seed)
     else:
         for rid in range(n_requests):
@@ -562,7 +605,7 @@ def serve_config(scfg: ServeConfig) -> dict:
             max_steps=n_requests * (max_new + 4 + shared_prefix))
     summary = engine.metrics.summary()
     prefix_stats = None
-    if prefix_cache:
+    if scfg.prefix_cache:
         kvp = engine.kv.stats().get("prefix", {})
         prefix_stats = {**summary["prefix_cache"],
                         "pages_saved": kvp.get("pages_saved", 0),
@@ -572,6 +615,9 @@ def serve_config(scfg: ServeConfig) -> dict:
     return {
         "config": scfg.to_dict(),
         "mesh": scfg.mesh,
+        "layers": engine.model.cfg.n_layers,
+        "param_bytes": int(sum(a.nbytes for a in
+                               jax.tree_util.tree_leaves(engine.params))),
         # rid -> greedy token ids, the bit-identity surface for the
         # mesh-vs-unsharded correctness checks (CI + bench mesh cells)
         "outputs": {int(r.rid): [int(t) for t in r.out_tokens]
@@ -625,7 +671,7 @@ def serve_config(scfg: ServeConfig) -> dict:
         "committed_gateway": (tuner.committed_gateway_params()
                               if tuner and tuner.gateway_region is not None
                               else None),
-        "kv_dtype": resolved_kv,
+        "kv_dtype": engine.kv.kv_dtype,
         "committed_kv_precision": (tuner.committed_kv_precision_params()
                                    if tuner and tuner.kv_regions else None),
     }
@@ -634,6 +680,11 @@ def serve_config(scfg: ServeConfig) -> dict:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve the published widths of --arch (default: "
+                         "its reduced CPU smoke-test config)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N whole layers")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=96)
@@ -714,6 +765,7 @@ def main() -> None:
                          "export'): local record beats golden, golden "
                          "beats cold")
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve_config(ServeConfig.from_args(args))
     def fmt(x, spec):
         return format(x, spec) if x is not None else "n/a"

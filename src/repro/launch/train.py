@@ -33,6 +33,7 @@ from ..distributed.fault_tolerance import (HeartbeatMonitor,
                                            StragglerWatchdog)
 from ..models import LayoutPlan, build_model
 from ..optim import adamw
+from . import enable_compile_cache
 from .steps import build_train_step
 
 
@@ -145,6 +146,7 @@ def main() -> None:
                     help="static-AT layout selection before step 0")
     ap.add_argument("--tune-shape", default="train_4k")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(arch=args.arch, steps=args.steps, reduced=args.reduced,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                 seq_len=args.seq_len, batch=args.batch, lr=args.lr,

@@ -30,11 +30,8 @@ def test_ring_attention_matches_reference():
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.ring_attention import make_ring_attention
 from repro.kernels import ref
-if hasattr(jax.sharding, "AxisType"):       # jax >= 0.6
-    mesh = jax.make_mesh((4,), ("model",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 B, H, S, D = 2, 4, 64, 16
 q = jax.random.normal(jax.random.PRNGKey(0), (B, H, S, D)) * 0.4
 k = jax.random.normal(jax.random.PRNGKey(1), (B, H, S, D)) * 0.4
@@ -61,11 +58,8 @@ def test_compressed_pod_psum():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-if hasattr(jax.sharding, "AxisType"):       # jax >= 0.6
-    mesh = jax.make_mesh((2,), ("pod",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((2,), ("pod",))
+mesh = jax.make_mesh((2,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 g = jax.random.normal(jax.random.PRNGKey(0), (2, 512))
 
 def reduce_fn(x):
@@ -74,10 +68,7 @@ def reduce_fn(x):
 def exact_fn(x):
     return jax.lax.psum(x, "pod")
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 with mesh:
     sm_c = jax.jit(shard_map(reduce_fn, mesh=mesh,
