@@ -1,0 +1,8 @@
+"""Device milliseconds per call of the chunked-prefill step program
+(``jit_paged_prefill_step``) in the traced window."""
+
+
+def read(run):
+    calls = (run.reduced.programs if run.reduced else {}).get(
+        "jit_paged_prefill_step")
+    return sum(calls) / len(calls) * 1e3 if calls else None
