@@ -27,6 +27,9 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
+
+from ..trace import span
 from . import paramfile
 from .cost import According
 from .errors import (OATMissingBasicParamError, OATParamCollisionError,
@@ -471,16 +474,20 @@ class ATContext:
         if region.prepro:
             region.prepro()
         try:
-            t0 = time.perf_counter()
-            if region.feature == "select":
-                fn = region.subregions[idx].fn
-                out = fn(*args, **kwargs)
-            else:
-                vals = list(region.varied.candidates())
-                pp = {b: vals[min(idx, len(vals) - 1)]
-                      for b in region.varied.names}
-                out = region.fn(*args, **pp, **kwargs)
-            cost = time.perf_counter() - t0
+            with span("at.candidate", region=region.name, idx=idx):
+                t0 = time.perf_counter()
+                if region.feature == "select":
+                    fn = region.subregions[idx].fn
+                    out = fn(*args, **kwargs)
+                else:
+                    vals = list(region.varied.candidates())
+                    pp = {b: vals[min(idx, len(vals) - 1)]
+                          for b in region.varied.names}
+                    out = region.fn(*args, **pp, **kwargs)
+                # JAX returns before the device is done: stop the clock
+                # on the output, not on its dispatch
+                jax.block_until_ready(out)
+                cost = time.perf_counter() - t0
         finally:
             if region.postpro:
                 region.postpro()

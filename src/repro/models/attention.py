@@ -146,31 +146,32 @@ def paged_decode(p: dict, x: jax.Array, k_pool: jax.Array,
     b, one, _ = x.shape
     psz = k_pool.shape[2]
     q, k, v = _project_qkv(p, x, cfg, pos[:, None])
-    phys = jnp.take_along_axis(page_table, (pos // psz)[:, None],
-                               axis=1)[:, 0]                      # (B,)
-    slot = pos % psz
-    pidx = phys[:, None, None, None]
-    hidx = jnp.arange(cfg.n_kv_heads)[None, :, None, None]
-    sidx = slot[:, None, None, None]
-    didx = jnp.arange(cfg.d_head)[None, None, None, :]
-    k_scale = v_scale = None
-    if scales is not None:
-        k_scale, v_scale = scales
-        kq, ks = quantize_int8_rows(k[:, :, :1, :])       # ks: (B, Hkv, 1)
-        vq, vs = quantize_int8_rows(v[:, :, :1, :])
-        k_pool = k_pool.at[pidx, hidx, sidx, didx].set(kq)
-        v_pool = v_pool.at[pidx, hidx, sidx, didx].set(vq)
-        sp = phys[:, None, None]
-        sh = jnp.arange(cfg.n_kv_heads)[None, :, None]
-        ss = slot[:, None, None]
-        k_scale = k_scale.at[sp, sh, ss].set(ks)
-        v_scale = v_scale.at[sp, sh, ss].set(vs)
-        scales = (k_scale, v_scale)
-    else:
-        k_pool = k_pool.at[pidx, hidx, sidx, didx].set(
-            k[:, :, :1, :].astype(k_pool.dtype))
-        v_pool = v_pool.at[pidx, hidx, sidx, didx].set(
-            v[:, :, :1, :].astype(v_pool.dtype))
+    with jax.named_scope("kv_write"):
+        phys = jnp.take_along_axis(page_table, (pos // psz)[:, None],
+                                   axis=1)[:, 0]                  # (B,)
+        slot = pos % psz
+        pidx = phys[:, None, None, None]
+        hidx = jnp.arange(cfg.n_kv_heads)[None, :, None, None]
+        sidx = slot[:, None, None, None]
+        didx = jnp.arange(cfg.d_head)[None, None, None, :]
+        k_scale = v_scale = None
+        if scales is not None:
+            k_scale, v_scale = scales
+            kq, ks = quantize_int8_rows(k[:, :, :1, :])   # ks: (B, Hkv, 1)
+            vq, vs = quantize_int8_rows(v[:, :, :1, :])
+            k_pool = k_pool.at[pidx, hidx, sidx, didx].set(kq)
+            v_pool = v_pool.at[pidx, hidx, sidx, didx].set(vq)
+            sp = phys[:, None, None]
+            sh = jnp.arange(cfg.n_kv_heads)[None, :, None]
+            ss = slot[:, None, None]
+            k_scale = k_scale.at[sp, sh, ss].set(ks)
+            v_scale = v_scale.at[sp, sh, ss].set(vs)
+            scales = (k_scale, v_scale)
+        else:
+            k_pool = k_pool.at[pidx, hidx, sidx, didx].set(
+                k[:, :, :1, :].astype(k_pool.dtype))
+            v_pool = v_pool.at[pidx, hidx, sidx, didx].set(
+                v[:, :, :1, :].astype(v_pool.dtype))
     kv_len = (pos + 1).astype(jnp.int32)
     pools = ops.PagedPools(k_pool, v_pool, k_scale, v_scale)
     out = ops.paged_decode(q, pools, page_table, kv_len, mesh=mesh)
@@ -247,28 +248,32 @@ def _paged_chunk_scatter(p: dict, x: jax.Array, k_pool: jax.Array,
     psz = k_pool.shape[2]
     positions = start[:, None] + jnp.arange(c)[None, :]       # (B, C)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    phys = jnp.take_along_axis(page_table, positions // psz, axis=1)
-    phys = jnp.where(positions < kv_len[:, None], phys, 0)    # null-page sink
-    slot = positions % psz
-    pidx = phys[:, None, :, None]                             # (B, 1, C, 1)
-    hidx = jnp.arange(cfg.n_kv_heads)[None, :, None, None]
-    sidx = slot[:, None, :, None]
-    didx = jnp.arange(cfg.d_head)[None, None, None, :]
-    if scales is not None:
-        k_scale, v_scale = scales
-        kq, ks = quantize_int8_rows(k)                # ks: (B, Hkv, C)
-        vq, vs = quantize_int8_rows(v)
-        k_pool = k_pool.at[pidx, hidx, sidx, didx].set(kq)
-        v_pool = v_pool.at[pidx, hidx, sidx, didx].set(vq)
-        sp = phys[:, None, :]                                 # (B, 1, C)
-        sh = jnp.arange(cfg.n_kv_heads)[None, :, None]
-        ss = slot[:, None, :]
-        k_scale = k_scale.at[sp, sh, ss].set(ks)
-        v_scale = v_scale.at[sp, sh, ss].set(vs)
-        scales = (k_scale, v_scale)
-    else:
-        k_pool = k_pool.at[pidx, hidx, sidx, didx].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[pidx, hidx, sidx, didx].set(v.astype(v_pool.dtype))
+    with jax.named_scope("kv_write"):
+        phys = jnp.take_along_axis(page_table, positions // psz, axis=1)
+        # null-page sink
+        phys = jnp.where(positions < kv_len[:, None], phys, 0)
+        slot = positions % psz
+        pidx = phys[:, None, :, None]                         # (B, 1, C, 1)
+        hidx = jnp.arange(cfg.n_kv_heads)[None, :, None, None]
+        sidx = slot[:, None, :, None]
+        didx = jnp.arange(cfg.d_head)[None, None, None, :]
+        if scales is not None:
+            k_scale, v_scale = scales
+            kq, ks = quantize_int8_rows(k)                # ks: (B, Hkv, C)
+            vq, vs = quantize_int8_rows(v)
+            k_pool = k_pool.at[pidx, hidx, sidx, didx].set(kq)
+            v_pool = v_pool.at[pidx, hidx, sidx, didx].set(vq)
+            sp = phys[:, None, :]                             # (B, 1, C)
+            sh = jnp.arange(cfg.n_kv_heads)[None, :, None]
+            ss = slot[:, None, :]
+            k_scale = k_scale.at[sp, sh, ss].set(ks)
+            v_scale = v_scale.at[sp, sh, ss].set(vs)
+            scales = (k_scale, v_scale)
+        else:
+            k_pool = k_pool.at[pidx, hidx, sidx, didx].set(
+                k.astype(k_pool.dtype))
+            v_pool = v_pool.at[pidx, hidx, sidx, didx].set(
+                v.astype(v_pool.dtype))
     return q, k_pool, v_pool, scales
 
 

@@ -357,6 +357,10 @@ def paged_decode_step(params: dict, caches: Any, page_table: jax.Array,
     token (B, 1) int32, pos (B,) int32, page_table (B, nblk) int32 shared
     by every layer (one logical->physical mapping per sequence; each layer
     has its own physical pool).  Returns (logits (B, V), caches).
+
+    Named scopes, in the ops' ``op_name``: ``layers`` (the scan), inside
+    it ``attn`` (with ``kv_write``, the pool scatter) and ``mlp``, then
+    ``lm_head``; :func:`paged_prefill_step` names its ops alike.
     """
     x = jnp.take(params["embed"], token, axis=0)
     x = _compute(x, cfg)
@@ -368,16 +372,18 @@ def paged_decode_step(params: dict, caches: Any, page_table: jax.Array,
         lp = scanned["params"]
         kp, vp = scanned["kv"]
         scales = scanned.get("kv_scale")
-        h, kp, vp, scales = attn.paged_decode(
-            lp["attn"], _norm(cfg, lp, x, "norm1"),
-            kp, vp, page_table, pos, acfg, scales, mesh=mesh)
-        x = x + h
-        h2 = _norm(cfg, lp, x, "norm2")
-        if kind == "attn_mlp":
-            x = x + _mlp_apply(lp["mlp"], h2, cfg)
-        else:
-            out, _ = moe_mod.apply_moe(lp["moe"], h2, moe_config(cfg))
-            x = x + out
+        with jax.named_scope("attn"):
+            h, kp, vp, scales = attn.paged_decode(
+                lp["attn"], _norm(cfg, lp, x, "norm1"),
+                kp, vp, page_table, pos, acfg, scales, mesh=mesh)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h2 = _norm(cfg, lp, x, "norm2")
+            if kind == "attn_mlp":
+                x = x + _mlp_apply(lp["mlp"], h2, cfg)
+            else:
+                out, _ = moe_mod.apply_moe(lp["moe"], h2, moe_config(cfg))
+                x = x + out
         states = {"kv": (kp, vp)}
         if scales is not None:
             states["kv_scale"] = scales
@@ -387,12 +393,14 @@ def paged_decode_step(params: dict, caches: Any, page_table: jax.Array,
                   "kv": caches["kv"]}
     if "kv_scale" in caches:        # int8 pools: thread the scales too
         scanned_in["kv_scale"] = caches["kv_scale"]
-    (x,), new_states = jax.lax.scan(body, (x,), scanned_in)
-    x = _norm(cfg, _cast_tree(
-        {k: params[k] for k in params if k.startswith("final_norm")}, cfg),
-        x, "final_norm")
-    w = _compute(lm_head_weight(params, cfg), cfg)
-    logits = (x[:, 0] @ w).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        (x,), new_states = jax.lax.scan(body, (x,), scanned_in)
+    with jax.named_scope("lm_head"):
+        x = _norm(cfg, _cast_tree(
+            {k: params[k] for k in params if k.startswith("final_norm")},
+            cfg), x, "final_norm")
+        w = _compute(lm_head_weight(params, cfg), cfg)
+        logits = (x[:, 0] @ w).astype(jnp.float32)
     return logits, _paged_out_caches(new_states)
 
 
@@ -421,16 +429,18 @@ def paged_prefill_step(params: dict, caches: Any, page_table: jax.Array,
         lp = scanned["params"]
         kp, vp = scanned["kv"]
         scales = scanned.get("kv_scale")
-        h, kp, vp, scales = attn.paged_prefill(
-            lp["attn"], _norm(cfg, lp, x, "norm1"),
-            kp, vp, page_table, start, kv_len, acfg, scales, mesh=mesh)
-        x = x + h
-        h2 = _norm(cfg, lp, x, "norm2")
-        if kind == "attn_mlp":
-            x = x + _mlp_apply(lp["mlp"], h2, cfg)
-        else:
-            out, _ = moe_mod.apply_moe(lp["moe"], h2, moe_config(cfg))
-            x = x + out
+        with jax.named_scope("attn"):
+            h, kp, vp, scales = attn.paged_prefill(
+                lp["attn"], _norm(cfg, lp, x, "norm1"),
+                kp, vp, page_table, start, kv_len, acfg, scales, mesh=mesh)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h2 = _norm(cfg, lp, x, "norm2")
+            if kind == "attn_mlp":
+                x = x + _mlp_apply(lp["mlp"], h2, cfg)
+            else:
+                out, _ = moe_mod.apply_moe(lp["moe"], h2, moe_config(cfg))
+                x = x + out
         states = {"kv": (kp, vp)}
         if scales is not None:
             states["kv_scale"] = scales
@@ -440,14 +450,16 @@ def paged_prefill_step(params: dict, caches: Any, page_table: jax.Array,
                   "kv": caches["kv"]}
     if "kv_scale" in caches:
         scanned_in["kv_scale"] = caches["kv_scale"]
-    (x,), new_states = jax.lax.scan(body, (x,), scanned_in)
-    x = _norm(cfg, _cast_tree(
-        {k: params[k] for k in params if k.startswith("final_norm")}, cfg),
-        x, "final_norm")
-    x_last = jnp.take_along_axis(
-        x, logit_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    w = _compute(lm_head_weight(params, cfg), cfg)
-    logits = (x_last @ w).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        (x,), new_states = jax.lax.scan(body, (x,), scanned_in)
+    with jax.named_scope("lm_head"):
+        x = _norm(cfg, _cast_tree(
+            {k: params[k] for k in params if k.startswith("final_norm")},
+            cfg), x, "final_norm")
+        x_last = jnp.take_along_axis(
+            x, logit_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        w = _compute(lm_head_weight(params, cfg), cfg)
+        logits = (x_last @ w).astype(jnp.float32)
     return logits, _paged_out_caches(new_states)
 
 

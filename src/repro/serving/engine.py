@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..trace import span
 from . import sampling
 from .buckets import LENGTH_BUCKETS
 from .kvcache import DenseKVCache, PagedKVCache, make_kv_cache
@@ -393,34 +394,38 @@ class ServingEngine:
                         "no other lane can be evicted")
                 self._preempt_lane(lane_id, priority=True)
                 continue
-            chunk = req.prompt[start:end] + [0] * (c - (end - start))
-            args = (self.params, self.kv.caches, self.kv.table_row(lane_id),
-                    jnp.asarray([chunk], jnp.int32),
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([end], jnp.int32),
-                    jnp.asarray([end - start - 1], jnp.int32))
-            if self.autotuner is not None \
-                    and getattr(self.autotuner, "prefill_regions", None):
-                logits, new_caches = self.autotuner.prefill(plen, c, *args)
-            else:
-                logits, new_caches = self._prefill_step(*args)
-            self.kv.caches = new_caches
-            self.prefill_chunks += 1
-            lane.pos = end
-            # every newly-FULL committed chunk-page becomes a shared,
-            # content-addressed index entry other admissions can hit
-            self.kv.publish_prefix(lane_id, req.prompt, end)
-            if end < plen:
-                continue                   # prompt still streaming in
-            tok = self._next_token(req, logits[0])
-            now = time.monotonic()
-            req.out_tokens.append(tok)
-            req.first_token_t = now
-            req.token_ts.append(now)
-            lane.phase = "decode"
-            lane.remaining = req.max_new_tokens - 1
-            if req.max_new_tokens <= 1 or self._is_eos(tok):
-                self._finish(lane_id, req, now)
+            with span("engine.prefill_chunk", rid=lane.rid, start=start,
+                      end=end):
+                chunk = req.prompt[start:end] + [0] * (c - (end - start))
+                args = (self.params, self.kv.caches,
+                        self.kv.table_row(lane_id),
+                        jnp.asarray([chunk], jnp.int32),
+                        jnp.asarray([start], jnp.int32),
+                        jnp.asarray([end], jnp.int32),
+                        jnp.asarray([end - start - 1], jnp.int32))
+                if self.autotuner is not None \
+                        and getattr(self.autotuner, "prefill_regions", None):
+                    logits, new_caches = self.autotuner.prefill(plen, c,
+                                                                *args)
+                else:
+                    logits, new_caches = self._prefill_step(*args)
+                self.kv.caches = new_caches
+                self.prefill_chunks += 1
+                lane.pos = end
+                # every newly-FULL committed chunk-page becomes a shared,
+                # content-addressed index entry other admissions can hit
+                self.kv.publish_prefix(lane_id, req.prompt, end)
+                if end < plen:
+                    continue               # prompt still streaming in
+                tok = self._next_token(req, logits[0])
+                now = time.monotonic()
+                req.out_tokens.append(tok)
+                req.first_token_t = now
+                req.token_ts.append(now)
+                lane.phase = "decode"
+                lane.remaining = req.max_new_tokens - 1
+                if req.max_new_tokens <= 1 or self._is_eos(tok):
+                    self._finish(lane_id, req, now)
 
     def _ensure_capacity(self) -> None:
         """Pre-decode page check: every decoding lane must own the page its
@@ -673,11 +678,13 @@ class ServingEngine:
         """Host-side scheduling half of a tick: time-slice victim, queue
         admissions, prefill chunks (their device work dispatches async;
         only a final chunk's token emission materialises)."""
-        victim = self.scheduler.pick_victim()
-        if victim is not None:
-            self._preempt_lane(victim)
-        self._admit()
-        self._prefill_tick()
+        with span("engine.schedule"):
+            with span("engine.admit"):
+                victim = self.scheduler.pick_victim()
+                if victim is not None:
+                    self._preempt_lane(victim)
+                self._admit()
+            self._prefill_tick()
 
     def dispatch(self) -> TickWork | None:
         """Dispatch the tick's batched decode step without waiting on it.
@@ -689,35 +696,39 @@ class ServingEngine:
         internally (their accept/reject rule is host-side by nature) and
         return None, as does a tick with no decoding lanes.
         """
-        if self.spec_k is not None:
-            self._spec_tick()
-            return None
-        self._ensure_capacity()
-        decoding = self.scheduler.decode_lanes()
-        if not decoding:
-            return None
-        token = np.zeros((self.n_lanes, 1), np.int32)
-        pos = np.zeros((self.n_lanes,), np.int32)
-        for i in decoding:
-            lane = self.scheduler.lanes[i]
-            req = self.active[lane.rid]
-            token[i, 0] = req.out_tokens[-1]
-            pos[i] = lane.pos
-        # mid-prefill lanes ride along in the fixed-shape batched step with
-        # a zeroed page-table row: their dummy KV write lands in the null
-        # page, never in their live prefill pages
-        extra = self.kv.decode_extra(
-            mask_lanes=self.scheduler.prefill_lanes())
-        args = (self.params, self.kv.caches, *extra,
-                jnp.asarray(token), jnp.asarray(pos))
-        if self.autotuner is not None:
-            kv_len = int(pos.max()) + 1
-            logits, new_caches = self.autotuner.decode(kv_len, *args)
-        else:
-            logits, new_caches = self._decode(*args)
-        self.kv.caches = new_caches
-        reqs = [self.active[self.scheduler.lanes[i].rid] for i in decoding]
-        return TickWork(logits=logits, decoding=decoding, reqs=reqs)
+        with span("engine.dispatch"):
+            if self.spec_k is not None:
+                self._spec_tick()
+                return None
+            with span("engine.dispatch.inputs"):
+                self._ensure_capacity()
+                decoding = self.scheduler.decode_lanes()
+                if not decoding:
+                    return None
+                token = np.zeros((self.n_lanes, 1), np.int32)
+                pos = np.zeros((self.n_lanes,), np.int32)
+                for i in decoding:
+                    lane = self.scheduler.lanes[i]
+                    req = self.active[lane.rid]
+                    token[i, 0] = req.out_tokens[-1]
+                    pos[i] = lane.pos
+                # mid-prefill lanes ride along in the fixed-shape batched
+                # step with a zeroed page-table row: their dummy KV write
+                # lands in the null page, never in their live prefill pages
+                extra = self.kv.decode_extra(
+                    mask_lanes=self.scheduler.prefill_lanes())
+                args = (self.params, self.kv.caches, *extra,
+                        jnp.asarray(token), jnp.asarray(pos))
+            with span("engine.dispatch.call", lanes=len(decoding)):
+                if self.autotuner is not None:
+                    kv_len = int(pos.max()) + 1
+                    logits, new_caches = self.autotuner.decode(kv_len, *args)
+                else:
+                    logits, new_caches = self._decode(*args)
+            self.kv.caches = new_caches
+            reqs = [self.active[self.scheduler.lanes[i].rid]
+                    for i in decoding]
+            return TickWork(logits=logits, decoding=decoding, reqs=reqs)
 
     def emit(self, work: TickWork | None) -> None:
         """Materialise a dispatched tick's logits (the first host-device
@@ -725,23 +736,33 @@ class ServingEngine:
         bookkeeping.  No-op for ``None`` (spec/idle ticks)."""
         if work is None:
             return
-        logits_np = np.asarray(work.logits)
-        toks = sampling.sample_batch(
-            logits_np[work.decoding], [r.sampling for r in work.reqs],
-            [len(r.out_tokens) for r in work.reqs])
-        now = time.monotonic()
-        self.steps += 1
-        for i, req, tok in zip(work.decoding, work.reqs, toks):
-            lane = self.scheduler.lanes[i]
-            req.out_tokens.append(tok)
-            req.token_ts.append(now)
-            lane.pos += 1
-            lane.remaining -= 1
-            lane.steps_served += 1
-            lane.tokens_served += 1
-            if lane.remaining <= 0 or self._is_eos(tok) \
-                    or lane.pos >= self.max_len - 1:
-                self._finish(i, req, now)
+        with span("engine.emit"):
+            with span("engine.emit.wait"):
+                # start the logits' copy before waiting, as a bare
+                # np.asarray would: the copy then follows the step on the
+                # device, with no host round trip between the two
+                work.logits.copy_to_host_async()
+                work.block()
+            with span("engine.emit.fetch"):
+                logits_np = np.asarray(work.logits)
+            with span("engine.emit.sample"):
+                toks = sampling.sample_batch(
+                    logits_np[work.decoding], [r.sampling for r in work.reqs],
+                    [len(r.out_tokens) for r in work.reqs])
+            with span("engine.emit.finish"):
+                now = time.monotonic()
+                self.steps += 1
+                for i, req, tok in zip(work.decoding, work.reqs, toks):
+                    lane = self.scheduler.lanes[i]
+                    req.out_tokens.append(tok)
+                    req.token_ts.append(now)
+                    lane.pos += 1
+                    lane.remaining -= 1
+                    lane.steps_served += 1
+                    lane.tokens_served += 1
+                    if lane.remaining <= 0 or self._is_eos(tok) \
+                            or lane.pos >= self.max_len - 1:
+                        self._finish(i, req, now)
 
     def step(self) -> None:
         self.schedule()

@@ -1,4 +1,6 @@
 """FIBER runtime semantics — paper §3.1–3.3, §4, §6.3."""
+import time
+
 import pytest
 
 from repro.core import (OAT_ALL, OAT_DYNAMIC, OAT_INSTALL, OAT_STATIC,
@@ -223,6 +225,42 @@ def test_dyn_perf_this_runs_optimised_without_tuning(ctx):
     out = ctx.OAT_DynPerfThis("PrecondSelect")
     assert out == {"eps": 0.3, "iter": 2}
     assert len(ctx.dynamic_state["PrecondSelect"].tried) == n_before
+
+
+def test_dynamic_clock_stops_when_the_output_is_ready(ctx):
+    """A candidate is timed until its output is ready, not until it was
+    dispatched: JAX returns before the device is done."""
+    class Dispatched:
+        """An output that is ready 50 ms after the call returned."""
+
+        ready = False
+
+        def block_until_ready(self):
+            time.sleep(0.05)
+            self.ready = True
+            return self
+
+    sel = dynamic_select(ctx, name="AsyncSelect", params=[])
+    issued = []
+
+    @sel.alternative()
+    def enqueue_only():
+        issued.append(Dispatched())
+        return issued[-1]
+
+    @sel.alternative()
+    def synchronous():
+        time.sleep(0.02)
+        return 0
+
+    sel.finalize()
+    ctx.OAT_ATexec(OAT_DYNAMIC, None)
+    for _ in range(2):
+        ctx.execute("AsyncSelect")
+    st = ctx.dynamic_state["AsyncSelect"]
+    assert issued[0].ready
+    assert st.tried[0] >= 0.05 > st.tried[1] >= 0.02
+    assert st.committed == 1
 
 
 def test_atdel_removes_candidate(ctx_with_bps):
