@@ -158,9 +158,11 @@ def kernel_phase(arch: str = "yi-6b", page_size: int = 16,
                 want = jax.jit(functools.partial(op, use_kernel=False))(
                     q, pl, *rest)
             for ns in (1, 2):
+                # decode at its default multi-page tile, prefill at a page
+                block_k = page_size if op_name == "prefill" else None
                 fn = jax.jit(functools.partial(op, use_kernel=True,
                                                num_splits=ns,
-                                               block_k=page_size))
+                                               block_k=block_k))
                 compiled = fn.lower(q, pl, *rest).compile()
                 require("tpu_custom_call" in compiled.as_text(),
                         f"{op_name}/{kv_dtype}: no Pallas kernel in HLO")

@@ -262,8 +262,9 @@ def paged_decode(q, pools: PagedPools, page_table, kv_len, *, mesh=None,
     Dispatch mirrors :func:`decode_attention`: the Pallas PagedAttention
     kernel on TPU, the gather+oracle reference on CPU.  Tuned PPs
     published under ``flash_paged_decode`` (the serving
-    ``DecodeAutoTuner`` publishes the per-bucket ``block_k`` sub-page
-    tile and the split-KV ``num_splits`` parallelism degree) flow into
+    ``DecodeAutoTuner`` publishes the per-bucket ``block_k`` tile, a
+    whole number of pages, and the split-KV ``num_splits`` parallelism
+    degree) flow into
     the kernel call; the page size itself is structural — it is fixed
     when the pool is built, not a per-call knob.  An int8 ``pools``
     bundle (scales present) switches both backends to the

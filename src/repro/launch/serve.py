@@ -202,7 +202,8 @@ def _make_kv_precision_bench(model, page_size: int, lanes: int = 2,
             (lanes, 1)).astype(np.int32))
         start = jnp.zeros((lanes,), jnp.int32)
         kv_len = jnp.full((lanes,), plen, jnp.int32)
-        at.publish("flash_paged_decode", block_k=block_k)
+        # block_k is a prefill tile (a divisor of the page); decode keeps
+        # its default multi-page tile
         at.publish("flash_paged_prefill", block_k=block_k)
         t0 = time.perf_counter()
         logits, caches = prefill_jit(params, caches, table, prompt,
@@ -249,7 +250,8 @@ def _make_autotuner(model, workdir: str, cache: str, page_size: int,
                     num_splits: int | None = None,
                     mesh=None, mesh_shape=None,
                     tuning_backend: str = "jsonl",
-                    golden_db: str | None = None):
+                    golden_db: str | None = None,
+                    table_pages: int | None = None):
     """Per-bucket dynamic select over decode variants (repro.at session).
 
     Each candidate gets its own jit cache and publishes its block PPs
@@ -271,12 +273,15 @@ def _make_autotuner(model, workdir: str, cache: str, page_size: int,
     throwaway pools as a cost proxy, and its greedy-agreement guard
     compares like with like either way.
 
+    ``table_pages`` (the page table's width) caps the decode tiles.
+
     ``tuning_backend`` selects the record store behind the session
     (``at.record_backends``: jsonl default, sqlite for concurrent
     workers); ``golden_db`` overlays a read-only fleet winner DB so a
     fresh deployment warm-loads committed optima it never measured.
     """
     from ..tuning import DecodeAutoTuner, divisor_block_ks
+    from ..tuning.dynamic import DEFAULT_BLOCK_KS, page_multiple_block_ks
     session = at.AutoTuner(workdir, record_backend=tuning_backend,
                            golden_db=golden_db)
 
@@ -286,12 +291,13 @@ def _make_autotuner(model, workdir: str, cache: str, page_size: int,
         return jax.jit(fn, **jit_kw)
 
     if cache == "paged":
-        # the paged kernel's run-time PPs are the split-K tile *within*
-        # a page (page size itself is structural, fixed at pool build)
-        # and the split-KV parallelism degree, so the per-bucket space is
-        # block_k in divisors{psz/2, psz} x num_splits; a forced
-        # --num-splits pins the candidate ladder to that single degree
-        # (1 always leads, keeping legacy winner indices valid)
+        # the decode kernel's run-time PPs are its tile, a whole number
+        # of pages capped at the table's width (page size itself is
+        # structural, fixed at pool build), and the split-KV parallelism
+        # degree, so the per-bucket space is block_k in page multiples of
+        # DEFAULT_BLOCK_KS x num_splits; a forced --num-splits pins the
+        # candidate ladder to that single degree (1 always leads, keeping
+        # legacy winner indices valid)
         splits = (1, 2, 4) if num_splits is None else (int(num_splits),)
 
         def make_decode(block_k, n_split):
@@ -306,9 +312,9 @@ def _make_autotuner(model, workdir: str, cache: str, page_size: int,
 
         tuner = DecodeAutoTuner(session, make_decode,
                                 buckets=REDUCED_BUCKETS,
-                                block_ks=divisor_block_ks(
-                                    page_size,
-                                    (max(1, page_size // 2), page_size)),
+                                block_ks=page_multiple_block_ks(
+                                    page_size, DEFAULT_BLOCK_KS,
+                                    table_pages),
                                 num_splits=splits,
                                 mesh_shape=mesh_shape)
         if prefill_chunk is not None:
@@ -547,7 +553,8 @@ def build_serving(scfg: ServeConfig):
                             num_splits=scfg.num_splits,
                             mesh=mesh, mesh_shape=scfg.mesh,
                             tuning_backend=scfg.tuning_backend,
-                            golden_db=scfg.golden_db) \
+                            golden_db=scfg.golden_db,
+                            table_pages=-(-max_len // page_size)) \
         if autotune else None
     resolved_kv = kv_dtype
     if kv_dtype == "auto":

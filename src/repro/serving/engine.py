@@ -719,7 +719,15 @@ class ServingEngine:
                     mask_lanes=self.scheduler.prefill_lanes())
                 args = (self.params, self.kv.caches, *extra,
                         jnp.asarray(token), jnp.asarray(pos))
-            with span("engine.dispatch.call", lanes=len(decoding)):
+            meta = {"lanes": len(decoding)}
+            if self.kv.kind == "paged":
+                # the decode kernel's tile and the blocks its walk takes
+                # (a lane's context is pos + 1 tokens)
+                tile = self.kv.decode_tile()
+                meta["kv_tile"] = tile
+                meta["kv_blocks"] = int(
+                    ((pos[decoding] + tile) // tile).sum())
+            with span("engine.dispatch.call", **meta):
                 if self.autotuner is not None:
                     kv_len = int(pos.max()) + 1
                     logits, new_caches = self.autotuner.decode(kv_len, *args)

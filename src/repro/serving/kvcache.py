@@ -63,8 +63,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..at.session import tuned
 from ..distributed.compression import (dequantize_int8, quantize_int8,
                                        quantize_int8_rows)
+from ..kernels.flash_attention import (paged_decode_block_k,
+                                       paged_decode_page_bytes)
 
 NULL_PAGE = 0
 
@@ -580,6 +583,20 @@ class PagedKVCache:
             tbl = tbl.copy()
             tbl[list(mask_lanes), :] = NULL_PAGE
         return (jnp.asarray(tbl),)
+
+    def decode_tile(self) -> int:
+        """Tokens per grid step of the paged decode kernel over this pool
+        and table, under the published ``block_k``: the kernel's own
+        resolver, with the KV heads one device holds."""
+        k = self.caches["kv"][0]                  # (L, P, Hkv, psz, D)
+        hkv = k.shape[2] // _mesh_model_axis(self.mesh)
+        return paged_decode_block_k(
+            tuned("flash_paged_decode").get("block_k"),
+            page_size=self.page_size, table_pages=self.max_blocks,
+            page_bytes=paged_decode_page_bytes(hkv, self.page_size,
+                                               k.shape[-1],
+                                               k.dtype.itemsize,
+                                               self.quantized))
 
     def table_row(self, lane: int) -> jax.Array:
         """This lane's logical->physical mapping, shaped (1, nblk) for the

@@ -14,7 +14,9 @@ children tile their parent:
   per chunk (``rid``, ``start``, ``end``);
 * ``engine.dispatch``: ``engine.dispatch.inputs`` (page capacity, token and
   position arrays, page table to the device), ``engine.dispatch.call``
-  (the decode step's enqueue, ``lanes``);
+  (the decode step's enqueue, ``lanes``; on a paged cache also
+  ``kv_tile``, the decode kernel's tokens per grid step, and
+  ``kv_blocks``, the blocks the decoding lanes' walks take in all);
 * ``engine.emit``: ``engine.emit.wait`` (the step's device work),
   ``engine.emit.fetch`` (logits to the host), ``engine.emit.sample``,
   ``engine.emit.finish`` (per-lane bookkeeping).

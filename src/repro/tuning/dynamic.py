@@ -99,6 +99,30 @@ def divisor_block_ks(page_size: int, block_ks) -> tuple[int, ...]:
     return tuple(out) or (page_size,)
 
 
+def page_multiple_block_ks(page_size: int, block_ks,
+                           table_pages: int | None = None
+                           ) -> tuple[int, ...]:
+    """Filter candidate decode tiles to whole numbers of pages.
+
+    The paged decode kernel walks ``block_k // page_size`` pages per grid
+    step; a candidate that is not a multiple of the page rounds up inside
+    the kernel (with a warning), so it is dropped here.  Candidates are
+    clamped to the page table's width (``table_pages`` pages; a longer
+    tile is the whole table), deduplicated preserving order, and one page
+    is the fallback when nothing survives.
+    """
+    cap = table_pages * page_size if table_pages else None
+    out: list[int] = []
+    for bk in block_ks:
+        bk = int(bk)
+        if bk <= 0 or bk % page_size:
+            continue
+        bk = min(bk, cap) if cap else bk
+        if bk not in out:
+            out.append(bk)
+    return tuple(out) or (page_size,)
+
+
 # -- region naming ----------------------------------------------------------
 #
 # Canonical region-name builder.  With no mesh (or any 1-device mesh) the

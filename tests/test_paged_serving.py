@@ -74,13 +74,16 @@ class TestPagedDecodeKernel:
         want = ref.paged_decode_ref(q, kp, vp, table, kv_len)
         got = flash_paged_decode(q, kp, vp, table, kv_len, interpret=True)
         np.testing.assert_allclose(got, want, atol=1e-5)
-        # block_k sub-page split-K tiling (the kernel's run-time AT PP)
-        got_sub = flash_paged_decode(q, kp, vp, table, kv_len,
-                                     block_k=psz // 2, interpret=True)
-        np.testing.assert_allclose(got_sub, want, atol=1e-5)
-        # a block_k that does not divide the page falls back to whole-page
-        got_bad = flash_paged_decode(q, kp, vp, table, kv_len,
-                                     block_k=3, interpret=True)
+        # block_k is a whole number of pages per grid step (the kernel's
+        # run-time AT PP): one page, and two over the 3-page table
+        for block_k in (psz, 2 * psz):
+            got_tile = flash_paged_decode(q, kp, vp, table, kv_len,
+                                          block_k=block_k, interpret=True)
+            np.testing.assert_allclose(got_tile, want, atol=1e-5)
+        # a block_k that is not a multiple of the page rounds up to one
+        with pytest.warns(RuntimeWarning, match="block_k=3"):
+            got_bad = flash_paged_decode(q, kp, vp, table, kv_len,
+                                         block_k=3, interpret=True)
         np.testing.assert_allclose(got_bad, want, atol=1e-5)
 
     def test_ops_dispatch_cpu(self):

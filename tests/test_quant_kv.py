@@ -123,9 +123,9 @@ class TestQuantKernels:
         got = flash_paged_decode_quant(q, k8, v8, ks, vs, table, kv_len,
                                        interpret=True)
         np.testing.assert_allclose(got, want, atol=1e-5)
-        # sub-page split-K tile (the tuned PP) must not change the result
+        # a one-page tile (the tuned PP) must not change the result
         got_sub = flash_paged_decode_quant(q, k8, v8, ks, vs, table,
-                                           kv_len, block_k=psz // 2,
+                                           kv_len, block_k=psz,
                                            interpret=True)
         np.testing.assert_allclose(got_sub, want, atol=1e-5)
         # the quantized answer tracks the fp pools it was built from

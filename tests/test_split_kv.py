@@ -202,7 +202,7 @@ def _paged_case(b=3, h=4, hkv=2, d=16, psz=8, p=10, nblk=4, qscale=0.3):
 
 
 class TestSplitDecodeKernel:
-    @pytest.mark.parametrize("block_k", [None, 4])
+    @pytest.mark.parametrize("block_k", [None, 8, 24])
     @pytest.mark.parametrize("num_splits", [1, 2, 4, 8])
     def test_matches_oracles(self, num_splits, block_k):
         q, kp, vp, table, kv_len = _paged_case()
@@ -340,7 +340,7 @@ class TestSplitPrefillKernel:
         q, kp, vp, table, kv_len = _paged_case(b=2)
         pools = ops.PagedPools(kp, vp)
         want = ref.paged_decode_ref(q, kp, vp, table, kv_len)
-        at.publish("flash_paged_decode", block_k=4, num_splits=4)
+        at.publish("flash_paged_decode", block_k=8, num_splits=4)
         got = ops.paged_decode(q, pools, table, kv_len, use_kernel=True)
         np.testing.assert_allclose(got, want, atol=1e-5)
 
@@ -361,10 +361,12 @@ class TestBlockKResolution:
         np.testing.assert_allclose(got, want, atol=1e-6)
 
     def test_divisor_block_k_does_not_warn(self):
+        """Decode tiles are whole pages: a multiple of the page is taken
+        as it is."""
         q, kp, vp, table, kv_len = _paged_case(b=2)
         with warnings.catch_warnings():
             warnings.simplefilter("error", RuntimeWarning)
-            flash_paged_decode(q, kp, vp, table, kv_len, block_k=4,
+            flash_paged_decode(q, kp, vp, table, kv_len, block_k=16,
                                interpret=True)
 
     def test_divisor_block_ks_filter(self):
@@ -376,6 +378,20 @@ class TestBlockKResolution:
         # nothing survives -> whole page fallback
         assert divisor_block_ks(16, (3, 5, 7)) == (16,)
         assert divisor_block_ks(8, ()) == (8,)
+
+    def test_page_multiple_block_ks_filter(self):
+        """The decode ladder keeps whole pages, clamped to the table."""
+        from repro.tuning import page_multiple_block_ks
+        from repro.tuning.dynamic import DEFAULT_BLOCK_KS
+        assert page_multiple_block_ks(16, DEFAULT_BLOCK_KS, 81) \
+            == (256, 512, 1024)
+        # clamp to the table's width, then dedup
+        assert page_multiple_block_ks(16, DEFAULT_BLOCK_KS, 20) == (256, 320)
+        assert page_multiple_block_ks(16, DEFAULT_BLOCK_KS, 6) == (96,)
+        # sub-page and other non-multiples dropped, order preserved
+        assert page_multiple_block_ks(16, (8, 48, 3, 16, 48)) == (48, 16)
+        # nothing survives -> one page
+        assert page_multiple_block_ks(16, (3, 8), 81) == (16,)
 
 
 # --------------------------------------------------------------------------

@@ -6,7 +6,8 @@ refuses what interpret mode accepts — block shapes the TPU tiling cannot
 take, more VMEM than a kernel may use, programs larger than the chip —
 so these tests catch them before chip time is spent.  Widths are yi-6b's
 published attention widths (bf16, 32 query heads over 4 KV heads: G=8,
-head dim 128, page 16) plus one deepseek-7b decode (MHA, G=1).
+head dim 128, page 16) plus one deepseek-7b decode (MHA, G=1), and the
+decode kernel at the benchmark cell's exact shapes.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and each test worker imports every
@@ -15,6 +16,8 @@ test file.
 import dataclasses
 import functools
 import importlib
+import re
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -107,16 +110,41 @@ def test_paged_kernel_compiles_for_v5e(one_chip, arch, op, quant,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("block_k", [PAGE // 2, PAGE])
+@pytest.mark.parametrize("block_k", [PAGE // 2, 4 * PAGE])
 def test_paged_decode_subpage_tile_compiles_for_v5e(one_chip, block_k):
-    """The run-time AT ladder's sub-page decode tiles (block_k in
-    {page/2, page}) both compile, fp and int8 alike."""
+    """Decode tiles are whole pages: a sub-page request (rounded up to one
+    page) and a four-page tile both compile, fp and int8 alike."""
     for quant in (False, True):
         kernel = functools.partial(KERNELS["decode", quant],
                                    block_k=block_k, num_splits=2)
-        compiled = jax.jit(kernel).lower(
-            *_kernel_args(one_chip, "yi-6b", "decode", quant)).compile()
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", RuntimeWarning)
+            compiled = jax.jit(kernel).lower(
+                *_kernel_args(one_chip, "yi-6b", "decode", quant)).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block_k", [None, 64, 128, 256, 512])
+def test_paged_decode_compiles_at_cell_shapes(one_chip, block_k):
+    """The benchmark cell's decode attention: 19 lanes, a 1,565-page bf16
+    pool, an 81-page table, at the default tile and each ladder value.
+    The custom call's instruction name starts with ``flash_paged_decode``,
+    which is what the benchmark's kernel reader matches."""
+    h, hkv, d = _widths("yi-6b")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((1565, hkv, PAGE, d), jnp.bfloat16)
+    kernel = functools.partial(fa.flash_paged_decode, block_k=block_k)
+    text = jax.jit(kernel).lower(
+        sds((19, h, 1, d), jnp.bfloat16), pool, pool,
+        sds((19, 81), jnp.int32), sds((19,), jnp.int32)).compile().as_text()
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert calls
+    assert all(re.match(r"flash_paged_decode", c) for c in calls), calls
 
 
 def test_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):

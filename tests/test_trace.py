@@ -45,3 +45,57 @@ def test_paged_steps_name_their_scopes(step):
     for scope in ("/layers/while/body/", "/attn/", "/attn/kv_write/",
                   "/mlp/", "/lm_head/"):
         assert scope in text, scope
+
+
+def test_dispatch_call_carries_the_decode_tile(monkeypatch):
+    """``engine.dispatch.call`` on a paged cache carries ``kv_tile``, the
+    decode kernel's resolved tile, and ``kv_blocks``, the sum over the
+    decoding lanes of ceil(context / tile), from host arrays alone."""
+    import numpy as np
+
+    from repro import at
+    from repro.kernels.flash_attention import (paged_decode_block_k,
+                                               paged_decode_page_bytes)
+    from repro.serving import Request, ServingEngine
+    from repro.serving import engine as engine_mod
+
+    cfg = get_arch("yi-6b").reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(model, params, n_lanes=2, max_len=64, cache="paged",
+                        page_size=8)
+    metas, positions = [], []
+
+    def record(name, **meta):
+        if name == "engine.dispatch.call":
+            metas.append(meta)
+        return span(name, **meta)
+
+    step = eng._decode
+
+    def decode(*args):
+        positions.append(np.asarray(args[-1]))
+        return step(*args)
+
+    monkeypatch.setattr(engine_mod, "span", record)
+    eng._decode = decode
+    # the default tile: the whole 8-page table, which the tiny pages fit
+    assert eng.kv.decode_tile() == paged_decode_block_k(
+        None, page_size=8, table_pages=8,
+        page_bytes=paged_decode_page_bytes(cfg.n_kv_heads, 8, cfg.head_dim,
+                                           4)) == 64
+    at.publish("flash_paged_decode", block_k=8)
+    try:
+        for rid, plen in enumerate((5, 11)):
+            eng.submit(Request(rid=rid, prompt=list(range(1, plen + 1)),
+                               max_new_tokens=6))
+        eng.run(max_steps=50)
+    finally:
+        at.clear_published()
+    assert metas and len(metas) == len(positions)
+    for meta, pos in zip(metas, positions):
+        live = pos[pos > 0]              # idle lanes ride along at pos 0
+        assert meta["lanes"] == live.size
+        assert meta["kv_tile"] == 8
+        assert meta["kv_blocks"] == int(np.sum(-(-(live + 1) // 8)))
+    assert max(m["kv_blocks"] for m in metas) > max(m["lanes"] for m in metas)
