@@ -122,12 +122,16 @@ def load_reference(name: str):
 def arch_config(config: dict):
     """The program's architecture config at the sizes the file states.
 
-    A configuration whose ``rms_norm_eps`` the program cannot take is
-    refused: a run that serves another eps than the file states is no
-    sound run of that configuration."""
+    The file's ``rms_norm_eps`` is served as the config's ``norm_eps``
+    where the program's config carries that field.  Where it does not, the
+    program's norms take ``rms_norm``'s default eps, and a configuration
+    that states another is refused: a run that serves another eps than the
+    file states is no sound run of that configuration."""
     from repro.configs import get_arch
     from repro.models import layers
 
+    if "rms_norm_eps" not in config:
+        raise ValueError("the configuration states no rms_norm_eps")
     heads = config["num_attention_heads"]
     dtype = config["torch_dtype"]
     arch = dataclasses.replace(
@@ -139,6 +143,8 @@ def arch_config(config: dict):
         rope_theta=float(config["rope_theta"]), param_dtype=dtype,
         compute_dtype=dtype, cache_dtype=dtype)
     eps = float(config["rms_norm_eps"])
+    if hasattr(arch, "norm_eps"):
+        return dataclasses.replace(arch, norm_eps=eps)
     fixed = inspect.signature(layers.rms_norm).parameters["eps"].default
     if eps != fixed:
         raise ValueError(f"the program's rms_norm eps is fixed at {fixed}; "

@@ -11,7 +11,7 @@ import time
 import numpy as np
 import pytest
 
-from benchroot import LIMIT_DECODE, make_root, stub_chip
+from benchroot import LIMIT_DECODE, LIMIT_MHA, make_root, stub_chip
 
 SEED = 2**33 + 11
 
@@ -42,9 +42,9 @@ def root(tmp_path, monkeypatch):
     return make_root(tmp_path)
 
 
-def _run(root, control=False):
+def _run(root, control=False, workload="tiny.decode"):
     from pb import harness
-    line = harness.run_cell("tiny.decode", SEED, 1.0, False, root=root,
+    line = harness.run_cell(workload, SEED, 1.0, False, root=root,
                             control=control)
     line.pop("_run")
     return line
@@ -52,6 +52,14 @@ def _run(root, control=False):
 
 def test_sound_run_is_correct(root):
     line = _run(root)
+    assert line["correct"] is True, line["checks"]
+
+
+def test_sound_mha_run_is_correct(root, monkeypatch):
+    """The same backlog on a model whose KV heads are its heads (group 1),
+    as deepseek-7b's are."""
+    stub_chip(monkeypatch, LIMIT_MHA)
+    line = _run(root, workload="tiny-mha.decode")
     assert line["correct"] is True, line["checks"]
 
 

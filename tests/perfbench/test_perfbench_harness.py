@@ -1,5 +1,6 @@
 """The harness on the CPU at toy sizes: the offline cell's ticks follow the
 seed and not the clock, and a run's result line has the contract's keys."""
+import dataclasses
 import json
 
 import pytest
@@ -101,12 +102,67 @@ def test_no_chip_no_result(tmp_path, capsys):
         harness.run_cell("tiny.decode", SEED, 1.0, False, root=tmp_path)
 
 
-def test_eps_the_program_cannot_take_is_refused():
+@dataclasses.dataclass(frozen=True)
+class FixedEpsArch:
+    """A program's architecture config whose norms take ``rms_norm``'s
+    default eps: the fields that ``arch_config`` sets, and no eps."""
+    n_layers: int = 0
+    d_model: int = 0
+    n_heads: int = 0
+    n_kv_heads: int = 0
+    d_head: int = 0
+    d_ff: int = 0
+    vocab_size: int = 0
+    rope_theta: float = 0.0
+    param_dtype: str = ""
+    compute_dtype: str = ""
+    cache_dtype: str = ""
+
+
+@dataclasses.dataclass(frozen=True)
+class EpsArch(FixedEpsArch):
+    """One whose norms take the eps its ``norm_eps`` field states."""
+    norm_eps: float = 1e-5
+
+
+def _conf(path):
+    import benchroot
+    return json.loads((benchroot.REPO / path).read_text())
+
+
+def test_eps_the_program_cannot_take_is_refused(monkeypatch):
     """A configuration is served with the ``rms_norm_eps`` it states, or
     not at all."""
-    import benchroot
+    import repro.configs
     from pb import harness
-    conf = json.loads((benchroot.DATA / "tiny.json").read_text())
+    conf = _conf("tests/perfbench/data/tiny.json")
+    assert harness.arch_config(conf).n_layers == conf["num_hidden_layers"]
+    monkeypatch.setattr(repro.configs, "get_arch",
+                        lambda name: FixedEpsArch())
     assert harness.arch_config(conf).n_layers == conf["num_hidden_layers"]
     with pytest.raises(ValueError, match="eps"):
         harness.arch_config(dict(conf, rms_norm_eps=1e-6))
+
+
+@pytest.mark.parametrize("path", ["tests/perfbench/data/tiny-mha.json",
+                                  "perfbench/configs/yi-6b.json",
+                                  "perfbench/configs/deepseek-7b.json"])
+def test_eps_the_file_states_reaches_the_program(monkeypatch, path):
+    """Where the program's config carries ``norm_eps``, the file's eps is
+    served in it: 1e-6 as the file states it, not refused."""
+    import repro.configs
+    from pb import harness
+    monkeypatch.setattr(repro.configs, "get_arch", lambda name: EpsArch())
+    conf = _conf(path)
+    arch = harness.arch_config(conf)
+    assert arch.norm_eps == conf["rms_norm_eps"]
+    assert arch.n_kv_heads == conf["num_key_value_heads"]
+    assert harness.arch_config(dict(conf, rms_norm_eps=1e-6)).norm_eps == 1e-6
+
+
+def test_configuration_without_eps_is_refused():
+    from pb import harness
+    conf = _conf("tests/perfbench/data/tiny.json")
+    del conf["rms_norm_eps"]
+    with pytest.raises(ValueError, match="rms_norm_eps"):
+        harness.arch_config(conf)
