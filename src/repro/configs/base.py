@@ -27,6 +27,7 @@ class ArchConfig:
     d_head: int = 0             # 0 => d_model // n_heads
     act: str = "silu"
     norm: str = "rms"           # rms | ln
+    norm_eps: float = 1e-5      # eps of every _norm (rms and ln alike)
     rope_theta: float = 10000.0
     use_rope: bool = True
     qkv_bias: bool = False

@@ -57,8 +57,9 @@ def mamba2_config(cfg: ArchConfig) -> ssm_mod.Mamba2Config:
 
 def _norm(cfg: ArchConfig, p: dict, x: jax.Array, name: str) -> jax.Array:
     if cfg.norm == "ln":
-        return layer_norm(x, p[f"{name}_w"], p[f"{name}_b"])
-    return rms_norm(x, p[f"{name}_w"])
+        return layer_norm(x, p[f"{name}_w"], p[f"{name}_b"],
+                          eps=cfg.norm_eps)
+    return rms_norm(x, p[f"{name}_w"], eps=cfg.norm_eps)
 
 
 def _init_norm(cfg: ArchConfig, name: str, dtype) -> dict:

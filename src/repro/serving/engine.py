@@ -721,12 +721,15 @@ class ServingEngine:
                         jnp.asarray(token), jnp.asarray(pos))
             meta = {"lanes": len(decoding)}
             if self.kv.kind == "paged":
-                # the decode kernel's tile and the blocks its walk takes
-                # (a lane's context is pos + 1 tokens)
+                # the decode kernel's tile, the blocks its walk takes and
+                # the live K and V bytes it reads (a lane's context is
+                # pos + 1 tokens)
                 tile = self.kv.decode_tile()
                 meta["kv_tile"] = tile
                 meta["kv_blocks"] = int(
                     ((pos[decoding] + tile) // tile).sum())
+                meta["kv_bytes"] = int((pos[decoding] + 1).sum()
+                                       * self.kv.kv_bytes_per_token())
             with span("engine.dispatch.call", **meta):
                 if self.autotuner is not None:
                     kv_len = int(pos.max()) + 1

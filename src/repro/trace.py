@@ -15,8 +15,11 @@ children tile their parent:
 * ``engine.dispatch``: ``engine.dispatch.inputs`` (page capacity, token and
   position arrays, page table to the device), ``engine.dispatch.call``
   (the decode step's enqueue, ``lanes``; on a paged cache also
-  ``kv_tile``, the decode kernel's tokens per grid step, and
-  ``kv_blocks``, the blocks the decoding lanes' walks take in all);
+  ``kv_tile``, the decode kernel's tokens per grid step,
+  ``kv_blocks``, the blocks the decoding lanes' walks take in all, and
+  ``kv_bytes``, the bytes of live K and V the decode kernel reads: the
+  decoding lanes' contexts times the pool's bytes per token, which is
+  layers x 2 x KV heads x head dim x cache itemsize);
 * ``engine.emit``: ``engine.emit.wait`` (the step's device work),
   ``engine.emit.fetch`` (logits to the host), ``engine.emit.sample``,
   ``engine.emit.finish`` (per-lane bookkeeping).

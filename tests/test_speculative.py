@@ -220,7 +220,7 @@ class TestSpecBitIdentity:
         assert eng.drafted_tokens >= eng.accepted_tokens
 
     def test_spec_greedy_matches_dense_moe_arch(self):
-        """Second bench config (deepseek-7b, MoE stack): same contract."""
+        """Second bench config (deepseek-7b, dense MHA stack): same contract."""
         cfg = ARCHS["deepseek-7b"].reduced()
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))

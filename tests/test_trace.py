@@ -47,19 +47,17 @@ def test_paged_steps_name_their_scopes(step):
         assert scope in text, scope
 
 
-def test_dispatch_call_carries_the_decode_tile(monkeypatch):
-    """``engine.dispatch.call`` on a paged cache carries ``kv_tile``, the
-    decode kernel's resolved tile, and ``kv_blocks``, the sum over the
-    decoding lanes of ceil(context / tile), from host arrays alone."""
+def _dispatch_metas(monkeypatch, cfg, block_k=None):
+    """The metadata of every ``engine.dispatch.call`` span and the
+    positions its step was called with, over two requests on a toy
+    engine with pages of 8 tokens; ``block_k`` is published as the
+    decode tile while they run."""
     import numpy as np
 
     from repro import at
-    from repro.kernels.flash_attention import (paged_decode_block_k,
-                                               paged_decode_page_bytes)
     from repro.serving import Request, ServingEngine
     from repro.serving import engine as engine_mod
 
-    cfg = get_arch("yi-6b").reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     eng = ServingEngine(model, params, n_lanes=2, max_len=64, cache="paged",
@@ -79,12 +77,8 @@ def test_dispatch_call_carries_the_decode_tile(monkeypatch):
 
     monkeypatch.setattr(engine_mod, "span", record)
     eng._decode = decode
-    # the default tile: the whole 8-page table, which the tiny pages fit
-    assert eng.kv.decode_tile() == paged_decode_block_k(
-        None, page_size=8, table_pages=8,
-        page_bytes=paged_decode_page_bytes(cfg.n_kv_heads, 8, cfg.head_dim,
-                                           4)) == 64
-    at.publish("flash_paged_decode", block_k=8)
+    if block_k is not None:
+        at.publish("flash_paged_decode", block_k=block_k)
     try:
         for rid, plen in enumerate((5, 11)):
             eng.submit(Request(rid=rid, prompt=list(range(1, plen + 1)),
@@ -93,9 +87,44 @@ def test_dispatch_call_carries_the_decode_tile(monkeypatch):
     finally:
         at.clear_published()
     assert metas and len(metas) == len(positions)
+    return eng, metas, positions
+
+
+def test_dispatch_call_carries_the_decode_tile(monkeypatch):
+    """``engine.dispatch.call`` on a paged cache carries ``kv_tile``, the
+    decode kernel's resolved tile, and ``kv_blocks``, the sum over the
+    decoding lanes of ceil(context / tile), from host arrays alone."""
+    import numpy as np
+
+    from repro.kernels.flash_attention import (paged_decode_block_k,
+                                               paged_decode_page_bytes)
+
+    cfg = get_arch("yi-6b").reduced()
+    eng, metas, positions = _dispatch_metas(monkeypatch, cfg, block_k=8)
+    # with nothing published, the default tile: the whole 8-page table,
+    # which the tiny pages fit
+    assert eng.kv.decode_tile() == paged_decode_block_k(
+        None, page_size=8, table_pages=8,
+        page_bytes=paged_decode_page_bytes(cfg.n_kv_heads, 8, cfg.head_dim,
+                                           4)) == 64
     for meta, pos in zip(metas, positions):
         live = pos[pos > 0]              # idle lanes ride along at pos 0
         assert meta["lanes"] == live.size
         assert meta["kv_tile"] == 8
         assert meta["kv_blocks"] == int(np.sum(-(-(live + 1) // 8)))
     assert max(m["kv_blocks"] for m in metas) > max(m["lanes"] for m in metas)
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "deepseek-7b"])
+def test_dispatch_call_carries_the_live_kv_bytes(monkeypatch, arch):
+    """``engine.dispatch.call`` on a paged cache carries ``kv_bytes``: the
+    decoding lanes' contexts times layers x 2 x KV heads x head dim x the
+    cache's itemsize, the live K and V the decode kernel reads."""
+    cfg = get_arch(arch).reduced()
+    _, metas, positions = _dispatch_metas(monkeypatch, cfg)
+    per_token = (cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim
+                 * jnp.dtype(cfg.cache_dtype).itemsize)
+    for meta, pos in zip(metas, positions):
+        live = pos[pos > 0]              # idle lanes ride along at pos 0
+        assert meta["kv_bytes"] == int((live + 1).sum()) * per_token
+    assert max(m["kv_bytes"] for m in metas) > 16 * per_token
